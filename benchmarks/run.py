@@ -43,15 +43,15 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--backend", default=None,
                     choices=("ref", "ell_pallas", "bsr"),
-                    help="kernels.ops backend override; Pallas backends fall "
-                         "back to interpret=True kernels when no TPU is "
-                         "attached instead of crashing")
+                    help="kernels.ops backend override; off-TPU the Pallas "
+                         "backends run in the interpreter (CPU numbers say "
+                         "nothing about chip speed)")
     args = ap.parse_args()
 
     if args.backend:
-        # Propagate to every DynLP/StreamEngine built downstream; ops
-        # resolves interpret=None to True off-TPU, so asking for a Pallas
-        # backend on a TPU-less host degrades to the interpreter.
+        # Propagate to every DynLP/StreamEngine built downstream.  Pallas
+        # kernels resolve interpret=None from the platform: compiled on a
+        # TPU, interpreted on a host without one.
         os.environ["REPRO_BACKEND"] = args.backend
         from repro.kernels import ops
         if args.backend != "ref" and not ops.on_tpu():

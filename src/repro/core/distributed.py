@@ -58,33 +58,20 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-if hasattr(jax, "shard_map"):  # jax ≥ 0.6
-    _shard_map = jax.shard_map
-else:  # jax ≤ 0.4.x ships it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The static replication checker has no rule for ``while`` on older jax
-# (and the check is advisory anyway) — disable it under whichever name
-# this version spells it.
-import inspect as _inspect
-
-_smap_params = _inspect.signature(_shard_map).parameters
-_CHECK_KW = (
-    {"check_rep": False} if "check_rep" in _smap_params
-    else {"check_vma": False} if "check_vma" in _smap_params
-    else {}
-)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_CHECK_KW)
-
 from repro.core.propagate import (PropagateResult, PropagationProblem,
-                                  bsr_update_island, update_island)
+                                  bsr_update_island, gather_rows,
+                                  update_island)
 from repro.graph.structures import PAD
 from repro.kernels.bsr_spmv import bsr_spmv, fill_bsr_blocks
 from repro.kernels.ell_propagate import ell_propagate_step
+from repro.kernels.platform import resolve_interpret
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    # the replication check is advisory, and the per-shard bodies (Pallas
+    # calls, the solve's while loop) are not annotated for it
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # "landmark" has no mesh body of its own: its hot solve IS the ref body
 # (the hot/cold split happens at staging, in the engine), so it rides the
@@ -226,8 +213,7 @@ def make_sharded_propagate_fn(
     delta_ = jnp.float32(delta)
     row = P(axes)  # rows sharded over ALL mesh axes (flattened view)
     row2 = P(axes, None)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     # bsr takes one extra row-sharded input (the per-edge tile-slot map)
     in_specs = ((row2, row2, row, row, row, row2, row, row)
@@ -283,13 +269,13 @@ def make_sharded_propagate_fn(
                 a small (m + D·e) concat buffer, never a full-length
                 temporary."""
                 ex = jax.lax.all_gather(x_loc[:e], axes, tiled=True)
-                return jnp.concatenate([x_loc, ex])[pos]
+                return gather_rows(jnp.concatenate([x_loc, ex]), pos)
         else:
             def gather_full(x_loc):
                 return jax.lax.all_gather(x_loc, axes, tiled=True)
 
             def gather_vals(x_loc):
-                return gather_full(x_loc)[idx]
+                return gather_rows(gather_full(x_loc), idx)
 
         if backend == "ell_pallas":
             # Pad the shard's row block to a multiple of the kernel tile
@@ -617,13 +603,7 @@ def sharded_cache_size() -> int:
     """Summed jit-cache entries of every streaming shard_map runner —
     folded into ``kernels.ops.compile_cache_size`` so the stream's
     recompile accounting covers the mesh path too."""
-    total = 0
-    for fn in _FN_CACHE.values():
-        try:
-            total += fn._cache_size()
-        except AttributeError:  # pragma: no cover — future jax rename
-            pass
-    return total
+    return sum(fn._cache_size() for fn in _FN_CACHE.values())
 
 
 # --------------------------------------------------------------------- #
@@ -732,10 +712,10 @@ def build_store_shard_plan(
         raise ValueError(
             f"store capacity {cap} not divisible by mesh device count "
             f"{n_dev}")
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if backend == "pallas" and interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from repro.kernels.argkmin import resolve_backend
+    backend = resolve_backend(backend)
+    if backend == "pallas":
+        interpret = resolve_interpret(interpret)
     fn_key, run = _store_sweep_for(
         mesh, backend=backend, block_rows=block_rows, interpret=interpret)
     key = (fn_key, (int(cap), int(dp)))
@@ -757,13 +737,7 @@ def store_sweep_cache_size() -> int:
     """Summed jit-cache entries of every sharded store-sweep runner —
     folded into ``ingest.ingest_cache_size`` so the ingest recompile gate
     covers the mesh path too."""
-    total = 0
-    for fn in _STORE_FN_CACHE.values():
-        try:
-            total += fn._cache_size()
-        except AttributeError:  # pragma: no cover — future jax rename
-            pass
-    return total
+    return sum(fn._cache_size() for fn in _STORE_FN_CACHE.values())
 
 
 def make_propagate_halo_fn(mesh, rows_per_shard: int, export_max: int,

@@ -48,11 +48,21 @@ class PropagationProblem(NamedTuple):
         return jnp.sum(self.wgt, axis=1) + self.wl0 + self.wl1
 
 
+def gather_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` for an (U, K) index block, gathered in (K, U) order.
+
+    Same values as ``x[idx]``.  The TPU compiler's time for the (U, K)
+    form grows with the index count (about 17 s at 82,560 x 24, the
+    ogbn-arxiv rung) while the lane-dense (K, U) form compiles in under a
+    second; every rung of the ladder pays this once per program."""
+    return x[idx.T].T
+
+
 def _gather_labels(f: jax.Array, nbr: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Gather neighbor labels; returns (labels, slot_mask)."""
     mask = nbr != PAD
     idx = jnp.where(mask, nbr, 0)
-    return f[idx], mask
+    return gather_rows(f, idx), mask
 
 
 def update_island(wgt, wl0, wl1, f, f_v, mask):
@@ -116,7 +126,7 @@ def _expand_frontier(problem: PropagationProblem, changed: jax.Array) -> jax.Arr
     the GPU-style scatter into a frontier queue."""
     mask = problem.nbr != PAD
     idx = jnp.where(mask, problem.nbr, 0)
-    return jnp.any(changed[idx] & mask, axis=1)
+    return jnp.any(gather_rows(changed, idx) & mask, axis=1)
 
 
 class PropagateResult(NamedTuple):

@@ -35,9 +35,10 @@ rung.  A ``bsr`` rung stages snapshots in the paper's Step-1 component
 order (``core.components.component_order``) so the adjacency densifies
 into tiles, derives the per-edge tile-slot map per Δ_t
 (``kernels.bsr_spmv.ell_bsr_layout``), and compiles one tile budget per
-rung — a Δ_t whose slot requirement overflows the budget falls back to
-``ell_pallas`` with a once-per-rung warning, mirroring the halo-overflow
-contract.
+rung — a Δ_t whose slot requirement overflows the budget runs on the
+backend the registry resolves for the rung with bsr out of the scan
+(``_bsr_twin``), with a once-per-rung warning, mirroring the
+halo-overflow contract.
 
 With ``mesh=`` the same stream spans a device mesh: rows of every bucket
 shard over all mesh axes through the ``core.distributed`` shard_map
@@ -126,8 +127,9 @@ class StreamStats:
     # mesh), "allgather", "halo", or "none" (no-op Δ_t, nothing solved)
     backend: str = "none"  # registry backend that solved this Δ_t
     # ("ref"/"ell_pallas"/"bsr"/"landmark"; "none" for a no-op Δ_t) — a
-    # bsr rung's slot-budget overflow shows up here as an "ell_pallas"
-    # batch; a "landmark" batch solved the hot working set only
+    # bsr rung's slot-budget overflow shows up here as its twin's name
+    # (``StreamEngine._bsr_twin``); a "landmark" batch solved the hot
+    # working set only
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -160,6 +162,10 @@ class _Pending:
     # landmark batches only: the cold unlabeled rows excluded from the
     # staged hot problem — drain serves them through the low-rank pass
     cold_ids: np.ndarray | None = None
+    # rows a LATER batch relabelled while this one was in flight: that
+    # relabel already reset their f, and the sequential order (solve, then
+    # relabel) says it wins, so drain must not overwrite them
+    relabelled: np.ndarray | None = None
 
 
 @dataclasses.dataclass
@@ -337,7 +343,7 @@ class StreamEngine:
         self._slot_budgets: dict[tuple[int, int], int] = {}
         self._slot_overflow_warned: set[tuple[int, int]] = set()
         self.bsr_batches = 0  # batches solved on the bsr backend
-        self.backend_overflows = 0  # bsr batches forced onto ell_pallas
+        self.backend_overflows = 0  # bsr batches forced onto the twin
         self._measured: dict[tuple[int, int], dict] = {}  # auto:measured
         # rungs whose auto:measured decision came from a PERSISTED probe
         # cache (core.persistence) instead of a fresh timed sweep
@@ -375,7 +381,7 @@ class StreamEngine:
         """Partition plan for one ladder rung — built once, then reused
         for every batch whose padded snapshot lands in that rung.  A bsr
         rung's slot-budget overflow additionally builds the rung's
-        ell_pallas twin (+1 plan per recorded overflow, like halo)."""
+        twin plan (+1 plan per recorded overflow, like halo)."""
         pkey = (key, backend, num_slots)
         plan = self._plans.get(pkey)
         if plan is None:
@@ -458,10 +464,18 @@ class StreamEngine:
             self._slot_overflow_warned.add(key)
             logger.warning(
                 "stream bsr: rung %s needs %d tile slots but the compiled "
-                "budget is %d — falling back to ell_pallas for this batch "
+                "budget is %d — falling back to %s for this batch "
                 "(warned once per rung)", key, needed,
-                self._slot_budgets[key])
+                self._slot_budgets[key], self._bsr_twin(key))
         self.backend_overflows += 1
+
+    def _bsr_twin(self, key: tuple[int, int]) -> str:
+        """The backend a bsr rung's overflow batches run on: what the
+        registry resolves for the rung with no block-fill measurement
+        (which keeps bsr out of the scan) and no landmark state."""
+        return ops.select_backend("auto", num_rows=key[0],
+                                  sharded=self.mesh is not None,
+                                  use_env=False)
 
     # ------------------------------------------------------------------ #
     def _note_touched(self, effect) -> None:
@@ -523,7 +537,9 @@ class StreamEngine:
         est, wsum = lm.cold_values(lm.landmark_values(g))
         ids = p.cold_ids
         sel = ids[wsum[ids] > 0]
-        g.f[sel] = est[sel]
+        live = sel if p.relabelled is None else sel[
+            ~np.isin(sel, p.relabelled)]  # later relabels win (see drain)
+        g.f[live] = est[live]
         p.view_f[sel] = est[sel]
         self.landmark_batches += 1
         self.landmark_cold_rows += len(sel)
@@ -532,8 +548,9 @@ class StreamEngine:
     def _stage_single(self, host: HostSnapshot) -> _Staging:
         """Resolve a mesh-less Δ_t: rung backend via the registry; bsr
         rungs component-reorder the rows (Step-1 clustering) and derive
-        the per-edge tile-slot map, falling back to ell_pallas when a
-        batch's slot requirement overflows the rung's compiled budget."""
+        the per-edge tile-slot map, falling back to the rung's twin
+        (``_bsr_twin``) when a batch's slot requirement overflows the
+        rung's compiled budget."""
         key = host.bucket_key
         backend = self._backend_modes.get(key)
         order = bl = staged = inv = None
@@ -555,7 +572,7 @@ class StreamEngine:
             bl = ell_bsr_layout(staged.nbr, self._bsr_block)
         if bl.num_slots > self._slot_budgets[key]:
             self._slot_overflow(key, bl.num_slots)
-            return _Staging(staged=host, backend="ell_pallas",
+            return _Staging(staged=host, backend=self._bsr_twin(key),
                             transport="single")
         self.bsr_batches += 1
         return _Staging(staged=staged, backend="bsr", transport="single",
@@ -580,9 +597,10 @@ class StreamEngine:
         layout is then identical in both programs, which is what makes
         bsr labels bit-identical across transports — and a batch whose
         tile-slot requirement overflows the rung's compiled budget runs
-        on the rung's ell_pallas twin under the same transport routing
-        (warned once per rung; ell_pallas is itself bit-identical across
-        transports, so the cross-transport contract survives fallback).
+        on the rung's twin (``_bsr_twin``) under the same transport
+        routing (warned once per rung; every exact backend is itself
+        bit-identical across transports, so the cross-transport contract
+        survives fallback).
         """
         key = host.bucket_key
         n_dev = self.mesh.devices.size
@@ -644,12 +662,11 @@ class StreamEngine:
             if bl is None:
                 bl = ell_bsr_layout(staged.nbr, self._bsr_block)
             if bl.num_slots > self._slot_budgets[key]:
-                # slot-budget overflow: this Δ_t rides the rung's
-                # ell_pallas twin but keeps the rung's TRANSPORT routing
-                # below, so halo accounting (halo_batches + overflows)
-                # stays exact
+                # slot-budget overflow: this Δ_t rides the rung's twin
+                # but keeps the rung's TRANSPORT routing below, so halo
+                # accounting (halo_batches + overflows) stays exact
                 self._slot_overflow(key, bl.num_slots)
-                backend_this = "ell_pallas"
+                backend_this = self._bsr_twin(key)
             else:
                 slot, num_slots = bl.slot, self._slot_budgets[key]
                 self.bsr_batches += 1
@@ -798,6 +815,14 @@ class StreamEngine:
         # ---- Step 1: change adjustment & sparsification (host) ----
         effect = g.apply_batch(batch, tau=self.tau, selector=self.ingestor)
         m = len(effect.new_ids)
+        if self._pending is not None and batch.rel_ids is not None \
+                and len(batch.rel_ids):
+            rel = np.asarray(batch.rel_ids, np.int64)
+            rel = rel[(rel >= 0) & (rel < g.num_nodes)]
+            rel = rel[g.alive[rel]]  # the relabels apply_batch applied
+            p = self._pending
+            p.relabelled = (rel if p.relabelled is None
+                            else np.union1d(p.relabelled, rel))
         if self._lm is not None:
             self._note_touched(effect)
 
@@ -940,7 +965,11 @@ class StreamEngine:
             # halo/bsr batches solved in a permuted row order: gather the
             # original rows back through the layout's inverse permutation
             solved = f[p.rows] if p.rows is not None else f[: len(p.unl_ids)]
-            self.graph.f[p.unl_ids] = solved
+            if p.relabelled is None:
+                self.graph.f[p.unl_ids] = solved
+            else:
+                keep = ~np.isin(p.unl_ids, p.relabelled)
+                self.graph.f[p.unl_ids[keep]] = solved[keep]
             p.view_f[p.unl_ids] = solved
             iterations = int(p.res.iterations)
             converged = bool(p.res.converged)

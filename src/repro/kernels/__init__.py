@@ -4,7 +4,8 @@
 `run_propagation` (see docs/backends.md).  The kernel modules back the
 registered backends — `propagate_pallas` (fused ELL), `bsr_spmv` (MXU
 tiles), `landmark_propagate` (hot/cold approximate staging) — plus the
-ingest argkmin pass and the Shiloach–Vishkin hook used for component
-reordering.  The layer stays optional: every backend has an exact XLA
-reference path, so TPU-less environments degrade instead of crashing.
+ingest argkmin pass and the Shiloach–Vishkin hook.  `platform.py`
+decides where a kernel runs: compiled on a TPU, interpreted only where
+no TPU exists (`interpret=None` everywhere).  Every backend also has an
+exact XLA reference path (`ref`, the argkmin `xla` twin).
 """

@@ -21,17 +21,21 @@ self-matches are excluded by the ``store_row == base_id + query_row``
 diagonal.
 
 Grid: (C // R,) over store row tiles.  The batch block and the running
-(M, TK) best-candidate accumulator use constant index maps (VMEM
-resident across grid steps, ``@pl.when`` init at step 0 — the standard
-cross-step accumulation pattern); the displacement mask is written per
-tile.  Ties select the lowest store row, matching both ``lax.top_k``
-and the host oracle's canonical order, so mass-duplicate inputs keep
-identical candidate coverage on every path.
+best-candidate accumulator — an (M, 128) lane block whose first TK lanes
+hold the list — use constant index maps (VMEM resident across grid
+steps, ``@pl.when`` init at step 0 — the standard cross-step
+accumulation pattern); the displacement mask is written per tile.
+Per-row masks and thresholds travel as (1, C) int32/f32 rows, so Mosaic
+sees only 2-D, lane-dense operands.  Ties select the lowest store row,
+matching both ``lax.top_k`` and the host oracle's canonical order, so
+mass-duplicate inputs keep identical candidate coverage on every path.
+Both paths contract at ``Precision.HIGHEST`` (full f32), which is what
+``selection_slack`` is sized for.
 
 The ``xla`` twin (one fused jit: matmul + ``lax.top_k`` + mask) serves
 non-TPU hardware; ``backend="auto"`` picks Pallas on TPU, XLA elsewhere.
-Interpret-mode Pallas is only used to *verify* agreement in tests and
-``benchmarks/ingest_lp.py --check``.
+Off-TPU the Pallas pass runs interpreted — tests and
+``benchmarks/ingest_lp.py --check`` use that to verify agreement.
 
 **Sharded sweep (move-the-batch orientation).**  When the store is
 row-sharded over a mesh (``ingest.ShardedEmbeddingStore``), each device
@@ -55,63 +59,87 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.graph.knn import SELECT_MARGIN
+from repro.kernels.platform import on_tpu, resolve_interpret
 
 _INT_MAX = 2**31 - 1  # python literal: a jnp scalar here would be a captured tracer in the kernel
+_LANES = 128  # the running top-k lists live in one lane-aligned (M, 128) block
+
+# Both paths contract in full float32: ``selection_slack`` is sized for f32
+# accumulation, and a TPU dot left at its default precision would round
+# through bfloat16 and drift by ~1e-3 — far past the slack.
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
-def _on_tpu() -> bool:
-    # mirrors kernels.ops.on_tpu; inlined because ops pulls in
-    # core.propagate, which imports this package — circular either way
-    return jax.default_backend() == "tpu"
-
-
-def _kernel(store_ref, valid_ref, kth_ref, batch_ref, bvalid_ref,
-            base_ref, slack_ref, row0_ref, val_ref, idx_ref, disp_ref, *,
-            topk):
+def _kernel(sc_ref, store_ref, valid_ref, thr_ref, batch_ref, bvalid_ref,
+            val_ref, idx_ref, disp_ref, *, topk):
+    # Every operand is 2-D with a lane-dense minor axis: per-row masks and
+    # thresholds ride as (1, R) rows and batch flags as an (M, 1) column,
+    # int32 instead of bool, so Mosaic never has to reshape a 1-D vector.
     i = pl.program_id(0)
     tile = store_ref[...]  # (R, D)
     batch = batch_ref[...]  # (M, D) — VMEM resident across tiles
     r = tile.shape[0]
     m = batch.shape[0]
-    base_id = base_ref[0]
-    # row0 is this store block's global row offset (0 single-device; the
-    # shard's offset under the sharded sweep) — all row ids downstream of
-    # rows_g are global, so per-shard outputs merge without renumbering
-    rows_g = row0_ref[0] + i * r + jax.lax.iota(jnp.int32, r)
+    # sc = (row0, base_id): row0 is this store block's global row offset (0
+    # single-device; the shard's offset under the sharded sweep) — all row
+    # ids downstream of rows_g are global, so per-shard outputs merge
+    # without renumbering
+    base_id = sc_ref[1]
+    rows_g = sc_ref[0] + i * r + jax.lax.broadcasted_iota(jnp.int32, (1, r), 1)
+    q_ids = base_id + jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
 
-    s = jnp.dot(batch, tile.T, preferred_element_type=jnp.float32)  # (M, R)
+    s = jax.lax.dot_general(batch, tile, (((1,), (1,)), ((), ())),
+                            precision=_PRECISION,
+                            preferred_element_type=jnp.float32)  # (M, R)
     w = (s + 1.0) * 0.5
-    self_mask = rows_g[None, :] == (base_id + jax.lax.iota(jnp.int32, m)[:, None])
-    col_ok = valid_ref[...][None, :] & ~self_mask
-    wm = jnp.where(col_ok, w, -jnp.inf)
+    valid = valid_ref[...] != 0  # (1, R)
+    wm = jnp.where(valid & (rows_g != q_ids), w, -jnp.inf)
 
     # displacement pruning: old valid rows some batch point beats
-    old = valid_ref[...] & (rows_g < base_id)
-    wq = jnp.where(bvalid_ref[...][:, None], w, -jnp.inf)
-    colmax = jnp.max(wq, axis=0)  # (R,)
-    disp_ref[...] = old & (colmax > kth_ref[...] - slack_ref[0])
+    # (thr = kth - slack, precomputed per row)
+    wq = jnp.where(bvalid_ref[...] != 0, w, -jnp.inf)
+    colmax = jnp.max(wq, axis=0, keepdims=True)  # (1, R)
+    old = valid & (rows_g < base_id)
+    disp_ref[...] = (old & (colmax > thr_ref[...])).astype(jnp.int32)
 
-    # fold this tile into the running top-TK (ties -> lowest store row)
+    # fold this tile into the running top-k (ties -> lowest store row); the
+    # accumulator's lanes past topk hold -inf and never win
     @pl.when(i == 0)
     def _init():
         val_ref[...] = jnp.full(val_ref.shape, -jnp.inf, jnp.float32)
         idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
-    cand_val = jnp.concatenate([val_ref[...], wm], axis=1)  # (M, TK+R)
-    cand_idx = jnp.concatenate(
-        [idx_ref[...], jnp.broadcast_to(rows_g[None, :], (m, r))], axis=1)
-    vals, idxs = [], []
-    for _ in range(topk):
-        mx = jnp.max(cand_val, axis=1)
-        tie = cand_val == mx[:, None]
-        sel = jnp.min(jnp.where(tie, cand_idx, _INT_MAX), axis=1)
-        vals.append(mx)
-        idxs.append(sel)
-        cand_val = jnp.where(tie & (cand_idx == sel[:, None]), -jnp.inf, cand_val)
-    val_ref[...] = jnp.stack(vals, axis=1)
-    idx_ref[...] = jnp.stack(idxs, axis=1)
+    tile_i = jnp.broadcast_to(rows_g, (m, r))
+    lane = jax.lax.broadcasted_iota(jnp.int32, val_ref.shape, 1)
+
+    def pick(t, carry):
+        """Move the t-th best (value, lowest id) of accumulator + tile to
+        output lane t, and retire it from whichever side held it."""
+        acc_v, acc_i, wm, out_v, out_i = carry
+        mx = jnp.maximum(jnp.max(acc_v, axis=1, keepdims=True),
+                         jnp.max(wm, axis=1, keepdims=True))  # (M, 1)
+        acc_tie = acc_v == mx
+        tile_tie = wm == mx
+        sel = jnp.minimum(
+            jnp.min(jnp.where(acc_tie, acc_i, _INT_MAX), axis=1, keepdims=True),
+            jnp.min(jnp.where(tile_tie, tile_i, _INT_MAX), axis=1,
+                    keepdims=True))
+        out_v = jnp.where(lane == t, mx, out_v)
+        out_i = jnp.where(lane == t, sel, out_i)
+        acc_v = jnp.where(acc_tie & (acc_i == sel), -jnp.inf, acc_v)
+        wm = jnp.where(tile_tie & (tile_i == sel), -jnp.inf, wm)
+        return acc_v, acc_i, wm, out_v, out_i
+
+    _, _, _, out_v, out_i = jax.lax.fori_loop(
+        0, topk, pick,
+        (val_ref[...], idx_ref[...], wm,
+         jnp.full(val_ref.shape, -jnp.inf, jnp.float32),
+         jnp.zeros(idx_ref.shape, jnp.int32)))
+    val_ref[...] = out_v
+    idx_ref[...] = out_i
 
 
 def _argkmin_pallas_impl(store, valid, kth, batch, batch_valid, base_id,
@@ -122,34 +150,36 @@ def _argkmin_pallas_impl(store, valid, kth, batch, batch_valid, base_id,
     m = batch.shape[0]
     r = min(block_rows, c)
     assert c % r == 0, (c, r)
-    row_spec = lambda width=None: pl.BlockSpec(
-        (r,) if width is None else (r, width),
-        (lambda i: (i,)) if width is None else (lambda i: (i, 0)))
-    const_spec = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+    assert topk <= _LANES, topk
+    const = lambda *shape: pl.BlockSpec(shape, lambda i, sc: (0,) * len(shape))
+    row_block = pl.BlockSpec((1, r), lambda i, sc: (0, i))
     val, idx, disp = pl.pallas_call(
         functools.partial(_kernel, topk=topk),
-        grid=(c // r,),
-        in_specs=[
-            row_spec(d),          # store tile
-            row_spec(),           # valid
-            row_spec(),           # kth
-            const_spec(m, d),     # batch
-            const_spec(m),        # batch_valid
-            const_spec(1),        # base_id
-            const_spec(1),        # slack
-            const_spec(1),        # row0 (global offset of this block)
-        ],
-        out_specs=[const_spec(m, topk), const_spec(m, topk), row_spec()],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # (row0, base_id)
+            grid=(c // r,),
+            in_specs=[
+                pl.BlockSpec((r, d), lambda i, sc: (i, 0)),  # store tile
+                row_block,        # valid (1, C) int32
+                row_block,        # thr = kth - slack (1, C)
+                const(m, d),      # batch
+                const(m, 1),      # batch_valid (M, 1) int32
+            ],
+            out_specs=[const(m, _LANES), const(m, _LANES), row_block],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((m, topk), jnp.float32),
-            jax.ShapeDtypeStruct((m, topk), jnp.int32),
-            jax.ShapeDtypeStruct((c,), jnp.bool_),
+            jax.ShapeDtypeStruct((m, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((m, _LANES), jnp.int32),
+            jax.ShapeDtypeStruct((1, c), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(store, valid, kth.astype(jnp.float32), batch, batch_valid,
-      jnp.full((1,), base_id, jnp.int32), jnp.full((1,), slack, jnp.float32),
-      jnp.full((1,), row0, jnp.int32))
-    return val, idx, disp
+    )(jnp.stack([jnp.asarray(row0, jnp.int32), jnp.asarray(base_id, jnp.int32)]),
+      store, valid.astype(jnp.int32).reshape(1, c),
+      (kth.astype(jnp.float32) - jnp.asarray(slack, jnp.float32)).reshape(1, c),
+      batch, batch_valid.astype(jnp.int32).reshape(m, 1))
+    return val[:, :topk], idx[:, :topk], disp[0] != 0
 
 
 _argkmin_pallas = jax.jit(
@@ -171,7 +201,8 @@ def _argkmin_xla_impl(store, valid, kth, batch, batch_valid, base_id, slack,
     # barrier stops XLA from folding the later transpose back into the
     # dot (which would silently restore the slow orientation)
     s = jax.lax.optimization_barrier(
-        jnp.dot(store, batch.T, preferred_element_type=jnp.float32))  # (C, M)
+        jnp.dot(store, batch.T, precision=_PRECISION,
+                preferred_element_type=jnp.float32))  # (C, M)
     w = (s + 1.0) * 0.5
     old = valid & (rows_g < base_id)
     colmax = jnp.max(jnp.where(batch_valid[None, :], w, -jnp.inf), axis=1)
@@ -259,18 +290,23 @@ def argkmin_candidates(
     (callers must drop them before canonical re-selection).
     """
     topk = min(k + SELECT_MARGIN, store.shape[0])
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+    backend = resolve_backend(backend)
     if backend == "pallas":
-        if interpret is None:
-            interpret = not _on_tpu()
         return _argkmin_pallas(store, valid, kth, batch, batch_valid,
-                               base_id, slack, 0, topk, block_rows, interpret)
+                               base_id, slack, 0, topk, block_rows,
+                               resolve_interpret(interpret))
     if backend == "xla":
         return _argkmin_xla(store, valid, kth, batch, batch_valid,
                             jnp.int32(base_id), jnp.float32(slack),
                             jnp.int32(0), topk)
     raise ValueError(f"unknown argkmin backend {backend!r}")
+
+
+def resolve_backend(backend: str) -> str:
+    """``"auto"`` -> the compiled Pallas pass on TPU, the XLA twin elsewhere."""
+    if backend == "auto":
+        return "pallas" if on_tpu() else "xla"
+    return backend
 
 
 def argkmin_cache_size() -> int:

@@ -36,6 +36,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 
 def _kernel(cols_ref, blocks_ref, x_ref, y_ref):
     i, j = pl.program_id(0), pl.program_id(1)
@@ -46,12 +48,15 @@ def _kernel(cols_ref, blocks_ref, x_ref, y_ref):
 
     @pl.when(cols_ref[i, j] >= 0)
     def _acc():
-        a = blocks_ref[0, 0]  # (BS, BS)
-        x = x_ref[...]  # (BS,)
-        y_ref[...] += jnp.dot(
-            a.astype(jnp.float32), x.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
+        a = blocks_ref[0, 0].astype(jnp.float32)  # (BS, BS)
+        # x is one (1, BS) lane row; broadcast it over a full sublane tile
+        # so the MXU sees an aligned (8, BS) x (BS, BS)^T product, and keep
+        # row 0: y[u] = sum_v a[u, v] * x[v]
+        x = jnp.broadcast_to(x_ref[...].astype(jnp.float32), (8, a.shape[1]))
+        y = jax.lax.dot_general(x, a, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+        y_ref[...] += y[:1]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -59,12 +64,15 @@ def bsr_spmv(
     blocks: jax.Array,  # (R, J, BS, BS) float — row-padded BSR tiles
     block_cols: jax.Array,  # (R, J) int32 — tile column ids, -1 = empty
     x: jax.Array,  # (C * BS,) float
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Block-sparse y = A @ x over `(R, J, BS, BS)` BSR tiles on the MXU.
 
     Empty tile slots carry `block_cols == -1` and are steered to a
     zero-weight read of column block 0, so padding never contributes.
+    ``x`` and ``y`` travel as (1, N) rows so every block is a lane-aligned
+    (1, BS) slice; the dot runs in full f32 (``Precision.HIGHEST``) so the
+    MXU path stays allclose to the XLA reference.
     """
     r, j, bs, _ = blocks.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -72,16 +80,18 @@ def bsr_spmv(
         grid=(r, j),
         in_specs=[
             pl.BlockSpec((1, 1, bs, bs), lambda i, jj, cols: (i, jj, 0, 0)),
-            pl.BlockSpec((bs,), lambda i, jj, cols: (jnp.maximum(cols[i, jj], 0),)),
+            pl.BlockSpec((1, bs),
+                         lambda i, jj, cols: (0, jnp.maximum(cols[i, jj], 0))),
         ],
-        out_specs=pl.BlockSpec((bs,), lambda i, jj, cols: (i,)),
+        out_specs=pl.BlockSpec((1, bs), lambda i, jj, cols: (0, i)),
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r * bs,), jnp.float32),
-        interpret=interpret,
-    )(block_cols, blocks, x)
+        out_shape=jax.ShapeDtypeStruct((1, r * bs), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(block_cols, blocks, x.reshape(1, -1))
+    return y[0]
 
 
 # --------------------------------------------------------------------- #

@@ -4,6 +4,10 @@ The CUDA version hooks each vertex to the min parent among its neighbors
 and then pointer-jumps ``par[i] = par[par[i]]``.  The TPU version fuses both
 into one pass over ELL row tiles with the parent vector VMEM-resident:
 hook is a masked row min-reduce (VPU), jump is a second gather.
+
+Like ``ell_propagate``, Mosaic refuses the in-kernel 1-D gathers ("Only 2D
+gather is supported"), so this kernel runs interpreted off-TPU only; the
+served path uses the XLA ``core.components.connected_components``.
 """
 
 from __future__ import annotations
@@ -13,6 +17,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 
 def _kernel(nbr_ref, par_ref, out_ref):
@@ -35,7 +41,7 @@ def cc_hook_step(
     nbr: jax.Array,  # (N, K) int32, PAD == -1
     par: jax.Array,  # (N,) int32
     block_rows: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """One fused Shiloach–Vishkin hook + path-halving jump over the ELL
     adjacency: per row, min over the neighbors' parents, then one jump
@@ -52,12 +58,13 @@ def cc_hook_step(
         ],
         out_specs=pl.BlockSpec((r,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(nbr, par)
     return out
 
 
-def connected_components_pallas(nbr, max_iters: int = 10_000, interpret=True,
+def connected_components_pallas(nbr, max_iters: int = 10_000,
+                                interpret: bool | None = None,
                                 block_rows: int = 512):
     """Full SV loop built on the kernel (hook+jump until fixpoint).
 

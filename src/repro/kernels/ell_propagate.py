@@ -12,6 +12,12 @@ the output F' use a constant index_map (whole-vector VMEM residency).
 
 out[0] = F'        (N,)  updated labels (only frontier rows move)
 out[1] = changed   (N,)  |ΔF| > δ flags (drives the next frontier)
+
+Mosaic does not lower this kernel: the per-edge read ``F[nbr]`` is a 1-D
+dynamic gather from VMEM, and the TPU compiler refuses it ("Only 2D gather
+is supported").  It runs interpreted off-TPU only, and the backend registry
+never auto-selects it (``kernels.ops``); making it compile needs a
+different gather strategy.
 """
 
 from __future__ import annotations
@@ -21,6 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 
 def _kernel(nbr_ref, wgt_ref, wl0_ref, wl1_ref, frontier_ref, f_ref,
@@ -64,7 +72,7 @@ def ell_propagate_step(
     f: jax.Array,  # (Nf,) float32 — Nf ≥ N; the gathered GLOBAL labels
     delta: float = 1e-4,
     block_rows: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
     row_offset: jax.Array | int = 0,
 ) -> tuple[jax.Array, jax.Array]:
     """One fused frontier sweep over ``nbr``'s rows.
@@ -104,7 +112,7 @@ def ell_propagate_step(
             jax.ShapeDtypeStruct((n,), jnp.float32),
             jax.ShapeDtypeStruct((n,), jnp.bool_),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(nbr, wgt, wl0.astype(jnp.float32), wl1.astype(jnp.float32),
       frontier, f.astype(jnp.float32), delta_arr, offset_arr)
     return fout, changed

@@ -22,8 +22,10 @@ Registered backends:
                        right answer on CPU and the allclose oracle
                        everywhere else.
   * ``"ell_pallas"`` — the fused ELL Pallas kernel loop
-                       (``propagate_pallas``): VPU path on TPU, interpret
-                       mode off-TPU.
+                       (``propagate_pallas``).  Explicit-only: its
+                       in-kernel VMEM gather does not lower on Mosaic,
+                       so it runs interpreted off-TPU and a TPU compile
+                       refuses it; auto never picks it.
   * ``"bsr"``        — block-sparse MXU path: the neighbor aggregation
                        runs as ``bsr_spmv`` over component-reordered
                        block-dense tiles built DIRECTLY from the ELL
@@ -56,9 +58,10 @@ backend whose ``auto_eligible`` accepts the problem; the
 ``REPRO_BACKEND`` environment variable replaces the *auto* default for
 fleet-wide flips (an explicitly passed backend still wins, and an env
 hint that names a backend unusable in the current mode degrades back to
-the auto scan instead of failing).  ``interpret`` defaults to True
-off-TPU, so Pallas backends *degrade to the interpreter instead of
-crashing* in TPU-less environments (CI, laptops).
+the auto scan instead of failing).  ``interpret=None`` resolves in
+``kernels.platform``: Pallas kernels compile on a TPU and run in the
+interpreter only where no TPU exists (CI, laptops) — a TPU never falls
+back to the interpreter on its own.
 
 ``donate=True`` routes through jit wrappers that donate the ``f0``
 buffer — the streaming engine feeds freshly staged device arrays every
@@ -80,16 +83,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.propagate import (PropagateResult, PropagationProblem,
-                                  bsr_update_island, propagate)
+                                  bsr_update_island, gather_rows, propagate)
 from repro.kernels.bsr_spmv import (bsr_spmv, dense_to_bsr,  # noqa: F401
                                     ell_bsr_layout, fill_bsr_blocks)
 from repro.kernels.cc_hook import cc_hook_step, connected_components_pallas  # noqa: F401
 from repro.kernels.ell_propagate import ell_propagate_step
-
-
-def on_tpu() -> bool:
-    """True when jax dispatches to a real TPU (not interpret mode)."""
-    return jax.default_backend() == "tpu"
+from repro.kernels.platform import on_tpu, resolve_interpret  # noqa: F401
 
 
 # Below this row count the fused kernels' launch overhead beats the work
@@ -291,8 +290,7 @@ def propagate_pallas(
     interpret: bool | None = None,
 ) -> PropagateResult:
     """Frontier propagation loop driven by the fused Pallas kernel."""
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     problem, n_orig = _pad_rows(problem, block_rows)
     n = problem.num_unlabeled
     f0 = jnp.pad(f0.astype(jnp.float32), (0, n - n_orig))
@@ -315,7 +313,7 @@ def propagate_pallas(
             interpret=interpret,
         )
         changed &= problem.valid
-        nbr_changed = jnp.any(changed[idx] & mask, axis=1)
+        nbr_changed = jnp.any(gather_rows(changed, idx) & mask, axis=1)
         new_frontier = (changed | nbr_changed) & problem.valid
         resid = jnp.max(jnp.abs(f_new - f), initial=0.0)
         return f_new, new_frontier, it + 1, resid
@@ -361,7 +359,7 @@ def _bsr_fixpoint(problem, slot, f0, frontier0, delta, max_iters, interpret,
         f_new = jnp.where(frontier & valid, f_all, f)
         resid = jnp.abs(f_new - f)
         changed = (resid > delta_) & valid
-        nbr_changed = jnp.any(changed[idx] & mask, axis=1)
+        nbr_changed = jnp.any(gather_rows(changed, idx) & mask, axis=1)
         new_frontier = (changed | nbr_changed) & valid
         return f_new, new_frontier, it + 1, jnp.max(resid, initial=0.0)
 
@@ -411,8 +409,7 @@ def propagate_bsr(
     layout in O(nnz), solves in the reordered space, and folds the labels
     back — no dense (U, U) intermediate at any size.
     """
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     if block_size is None:
         block_size = bsr_block_size()
     if slot is not None:
@@ -507,8 +504,7 @@ def _run_ref(problem, f0, frontier0, *, delta, max_iters, donate, **_):
 
 def _run_ell_pallas(problem, f0, frontier0, *, delta, max_iters, block_rows,
                     interpret, donate, **_):
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     block_rows = min(block_rows, problem.num_unlabeled)
     if donate:
         return _pallas_donating(problem, f0, frontier0, delta, max_iters,
@@ -562,8 +558,10 @@ register_backend(BackendSpec(
     sharded=True,
     transports=("allgather", "halo"),
     auto_priority=20,
-    auto_eligible=lambda info, hw: hw == "tpu" and (
-        info.num_rows is None or info.num_rows >= _PALLAS_MIN_ROWS),
+    # never auto: the kernel gathers F from VMEM by neighbor id, which
+    # Mosaic refuses ("Only 2D gather is supported"), so on a TPU it does
+    # not compile and off-TPU it would only ever run interpreted
+    auto_eligible=lambda info, hw: False,
     run=_run_ell_pallas,
     cache_entry_points=(lambda: propagate_pallas, lambda: _pallas_donating),
 ))
@@ -736,10 +734,7 @@ def compile_cache_size() -> int:
             if id(fn) in seen:
                 continue
             seen.add(id(fn))
-            try:
-                total += fn._cache_size()
-            except AttributeError:  # pragma: no cover — future jax rename
-                pass
+            total += fn._cache_size()
     from repro.core import distributed
 
     return total + distributed.sharded_cache_size()

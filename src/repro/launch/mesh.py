@@ -17,14 +17,10 @@ ICI_BW = 50e9  # bytes/s per link
 
 
 def make_mesh(shape, axes):
-    """Version-tolerant ``jax.make_mesh``: newer jax wants explicit
-    ``axis_types`` (``AxisType.Auto``) to opt out of sharding-in-types;
-    older jax (≤0.4.x) has neither the kwarg nor the enum."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (opting out of
+    sharding-in-types)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_stream_mesh(n_devices: int | None = None, axis: str = "data"):

@@ -14,12 +14,22 @@ stream's per-sweep collectives for free.  On CPU,
 ``host_devices=N`` forces an N-virtual-device host platform — the same
 ``--xla_force_host_platform_device_count`` idiom the multidevice tests
 and benchmarks use via subprocess env today.
+
+``enable_compile_cache`` places JAX's persistent compilation cache: where
+``JAX_COMPILATION_CACHE_DIR`` points when it is set (JAX reads the
+variable itself), else a fixed ``.jax_cache/`` at the root of the
+checkout.  The path is part of the cache key, so it never depends on a
+temporary name, a process id or the time.  Tests leave the cache off.
 """
 
 from __future__ import annotations
 
 import os
 import sys
+from pathlib import Path
+
+# <checkout>/src/repro/launch/platform.py -> <checkout>/.jax_cache
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # One flag per element so presence checks and joins stay trivial.
 GPU_XLA_FLAGS: tuple[str, ...] = (
@@ -78,3 +88,21 @@ def set_platform(platform: str | None = None, *,
             env,
             (f"--xla_force_host_platform_device_count={int(host_devices)}",))
     return env
+
+
+def enable_compile_cache(env: dict | None = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set in ``env`` (default
+    ``os.environ``) JAX already uses that directory and no other path is
+    set here; otherwise the cache goes to ``DEFAULT_COMPILE_CACHE``.
+    Call before the first compile."""
+    import jax
+
+    env = os.environ if env is None else env
+    path = env.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_COMPILE_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    return path

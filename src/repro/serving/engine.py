@@ -140,7 +140,10 @@ class ServiceDriver(threading.Thread):
 
     ``stop`` drains: in-flight tickets are fulfilled before the thread
     exits, and the batcher is closed so late submitters get a clean
-    error instead of hanging.
+    error instead of hanging.  If a tick raises (an admit, solve or
+    commit that failed), the loop ends and keeps the exception in
+    ``error``: queued tickets fail with it, and ``LPService`` re-raises
+    it to its next writer or ``stop`` — a dead clock never goes unseen.
     """
 
     def __init__(self, service, batcher: ReadBatcher, poll_ms: float = 2.0):
@@ -152,10 +155,19 @@ class ServiceDriver(threading.Thread):
         self.read_batches = 0  # fused gathers executed
         self.read_tickets = 0  # tickets fulfilled by those gathers
         self.deadline_admissions = 0  # windows admitted by the clock
+        self.error: BaseException | None = None  # what ended the loop
 
     def run(self):
         """Driver loop: fuse queued reads, pump the service's admission
         clock, exit only after a halt request has drained stragglers."""
+        try:
+            self._loop()
+        except Exception as e:  # re-raised to callers by LPService
+            self.error = e
+            for t in self._batcher.close():
+                t._fulfil(error=e)
+
+    def _loop(self):
         while True:
             tickets = self._batcher.take_all()
             if tickets:

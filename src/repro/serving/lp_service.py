@@ -273,6 +273,8 @@ class LPService:
             self._drained_reads = (rb + driver.read_batches,
                                    rt + driver.read_tickets,
                                    da + driver.deadline_admissions)
+            if driver.error is not None:
+                raise RuntimeError("service driver failed") from driver.error
 
     def close(self):
         """Stop the driver and flush: every queued mutation is admitted
@@ -354,6 +356,14 @@ class LPService:
             return
         self._last_ckpt_commit = self.engine.commits
         self.checkpoints_written += 1
+
+    def _raise_driver_error(self):
+        """Surface a failure that ended the background driver's loop (an
+        admit, solve or commit it clocked raised): writes must not keep
+        queueing behind a clock that stopped."""
+        d = self._driver
+        if d is not None and d.error is not None:
+            raise RuntimeError("service driver failed") from d.error
 
     def _raise_ckpt_error(self):
         """Surface an async checkpoint-write failure to the caller (the
@@ -538,6 +548,7 @@ class LPService:
                     "service preempted: state was checkpointed and the "
                     "driver halted — restart and restore to resume")
             self._raise_ckpt_error()
+            self._raise_driver_error()
             self.pump()  # harvest a finished solve / deadline-flush first
             if self._pending_ops() + ops > self.max_pending_ops:
                 if self.reject_on_overload:
@@ -627,6 +638,7 @@ class LPService:
         last commit's stats."""
         with self._lock:
             self._raise_ckpt_error()
+            self._raise_driver_error()
             self._admit()
             st = self.engine.drain()
             if st is not None:

@@ -1,23 +1,10 @@
-import importlib.util
 import os
-import sys
 
 # Tests must see exactly ONE device (the dry-run sets its own 512-device
 # flag inside launch/dryrun.py, never globally).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-try:
-    from hypothesis import settings
-except ModuleNotFoundError:  # optional test extra absent: use the fallback
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis",
-        os.path.join(os.path.dirname(__file__), "_hypothesis_fallback.py"),
-    )
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
-    from hypothesis import settings
+from hypothesis import settings  # noqa: E402
 
 settings.register_profile("ci", max_examples=25, deadline=None)
 settings.load_profile("ci")

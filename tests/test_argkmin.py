@@ -45,22 +45,46 @@ def _run(backend, embn, valid, kth, batch, bvalid, base_id, d, k, br=128):
         interpret=True)
 
 
+def _canonical_topk(embn, valid, batch, base_id, k):
+    """Host oracle: each batch row's canonical top-k over the valid store
+    (``pair_weights`` total order, self excluded)."""
+    c, m = len(embn), len(batch)
+    w = pair_weights(batch[:, None, :], embn[None, :, :])
+    ids = np.broadcast_to(np.arange(c, dtype=np.int64), w.shape).copy()
+    w = w.copy()
+    w[:, ~valid] = -np.inf
+    w[np.arange(m), base_id + np.arange(m)] = -np.inf
+    return topk_pairs(w, ids, k)[0]
+
+
 @pytest.mark.parametrize("dup", [False, True])
 @pytest.mark.parametrize("c,d,m,k", [(256, 16, 8, 5), (512, 33, 16, 3)])
 def test_xla_vs_pallas_interpret_agree(c, d, m, k, dup):
+    """The two paths contract in opposite orientations, so their fast
+    similarities may differ in the last bit.  What both must meet is the
+    contract canonical re-selection relies on: candidates that cover the
+    canonical top-k, fast values within rounding of the exact ones, and
+    a displacement mask that follows the slack rule exactly wherever the
+    rule is decided by more than rounding."""
     rng = np.random.default_rng(c + d + dup)
     embn, valid, kth, batch, bvalid, base_id = _make(rng, c, d, m, k, dup=dup)
-    vx, ix, dx = (np.asarray(a) for a in _run(
-        "xla", embn, valid, kth, batch, bvalid, base_id, d, k))
-    vp, ip, dp_ = (np.asarray(a) for a in _run(
-        "pallas", embn, valid, kth, batch, bvalid, base_id, d, k))
-    np.testing.assert_array_equal(dx, dp_)
-    for q in range(m):  # same candidate SET per query (order may differ
-        # only among equal values; both keep lowest ids)
-        sx = set(ix[q][np.isfinite(vx[q])])
-        sp = set(ip[q][np.isfinite(vp[q])])
-        assert sx == sp, q
-    np.testing.assert_array_equal(np.sort(vx, 1), np.sort(vp, 1))
+    want_i = _canonical_topk(embn, valid, batch, base_id, k)
+    w64 = (batch.astype(np.float64) @ embn.T.astype(np.float64) + 1.0) * 0.5
+    thr = kth.astype(np.float64) - selection_slack(d)
+    colmax = w64.max(axis=0)
+    rule = valid & (np.arange(c) < base_id) & (colmax > thr)
+    decided = np.abs(colmax - thr) > 1e-6
+    for backend in ("xla", "pallas"):
+        val, idx, disp = (np.asarray(a) for a in _run(
+            backend, embn, valid, kth, batch, bvalid, base_id, d, k))
+        fin = np.isfinite(val)
+        for q in range(m):
+            need = set(want_i[q][want_i[q] >= 0])
+            assert need <= set(idx[q][fin[q]]), (backend, q)
+        exact = w64[np.arange(m)[:, None], idx]
+        assert np.abs(val - exact)[fin].max() <= 1e-6, backend
+        np.testing.assert_array_equal(disp[decided], rule[decided],
+                                      err_msg=backend)
 
 
 def test_no_self_no_dead_candidates():
@@ -85,13 +109,7 @@ def test_candidates_cover_canonical_topk():
     rng = np.random.default_rng(11)
     c, d, m, k = 384, 24, 24, 5
     embn, valid, kth, batch, bvalid, base_id = _make(rng, c, d, m, k)
-    # canonical neighbors over the full valid store (excluding self)
-    w = pair_weights(batch[:, None, :], embn[None, :, :])
-    ids = np.broadcast_to(np.arange(c, dtype=np.int64), w.shape).copy()
-    w = w.copy()
-    w[:, ~valid] = -np.inf
-    w[np.arange(m), base_id + np.arange(m)] = -np.inf
-    want_i, want_w = topk_pairs(w, ids, k)
+    want_i = _canonical_topk(embn, valid, batch, base_id, k)
     for backend in ("xla", "pallas"):
         val, idx, _ = (np.asarray(a) for a in _run(
             backend, embn, valid, kth, batch, bvalid, base_id, d, k,

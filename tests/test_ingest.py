@@ -13,9 +13,6 @@ Core claims (ISSUE 7 acceptance):
     mixed stream — single-device here, forced 8-virtual-device mesh in
     the subprocess arm;
   * the ingest jit cache stays within the a-priori ladder bound.
-
-Strategies use only the surface shared by real hypothesis and the
-``tests/_hypothesis_fallback.py`` shim.
 """
 
 import os
@@ -165,16 +162,10 @@ SCRIPT_8DEV = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import importlib.util, sys
+    import sys
     sys.path.insert(0, {src!r})
     from repro.launch.mesh import make_stream_mesh
     import numpy as np
-    # load this module without conftest: stub hypothesis with the shim
-    spec = importlib.util.spec_from_file_location(
-        "hypothesis", os.path.join({tests!r}, "_hypothesis_fallback.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    sys.modules["hypothesis"] = mod
     sys.path.insert(0, {tests!r})
     from test_ingest import _mixed_service_stream
 

@@ -15,9 +15,6 @@ Core claims:
     restores mesh-less and a mesh-less engine restores sharded(8-dev),
     each continues streaming, and final labels stay bit-identical to an
     uninterrupted oracle (extends the PR-8 elastic-restore contract).
-
-Strategies use only the surface shared by real hypothesis and the
-``tests/_hypothesis_fallback.py`` shim.
 """
 
 import os
@@ -143,14 +140,9 @@ SCRIPT_8DEV = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import importlib.util, sys
+    import sys
     sys.path.insert(0, {src!r})
     import numpy as np
-    spec = importlib.util.spec_from_file_location(
-        "hypothesis", os.path.join({tests!r}, "_hypothesis_fallback.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    sys.modules["hypothesis"] = mod
     sys.path.insert(0, {tests!r})
     from test_ingest_sharded import run_sharded_vs_single
     from repro.ingest import ingest_cache_size, ingest_ladder_bound

@@ -7,7 +7,8 @@ one-shot backend init.
 
 import pytest
 
-from repro.launch.platform import GPU_XLA_FLAGS, set_platform
+from repro.launch.platform import (DEFAULT_COMPILE_CACHE, GPU_XLA_FLAGS,
+                                   enable_compile_cache, set_platform)
 
 
 def test_gpu_platform_installs_flag_set():
@@ -49,3 +50,33 @@ def test_validation_and_late_call_guard():
     # jax is imported in this process: mutating os.environ would be dead
     with pytest.raises(RuntimeError, match="before jax"):
         set_platform("cpu")
+
+
+@pytest.fixture
+def restore_cache_config():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_enable_compilation_cache)
+    yield jax
+    jax.config.update("jax_compilation_cache_dir", saved[0])
+    jax.config.update("jax_enable_compilation_cache", saved[1])
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_env_dir_wins(restore_cache_config):
+    jax = restore_cache_config
+    before = jax.config.jax_compilation_cache_dir
+    path = enable_compile_cache(env={"JAX_COMPILATION_CACHE_DIR": "/x/cc"})
+    assert path == "/x/cc"
+    # JAX reads the variable itself: nothing here overrides the directory
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(restore_cache_config):
+    jax = restore_cache_config
+    path = enable_compile_cache(env={})
+    assert path == str(DEFAULT_COMPILE_CACHE)
+    assert jax.config.jax_compilation_cache_dir == path
+    assert DEFAULT_COMPILE_CACHE.parent.joinpath("pyproject.toml").exists()

@@ -61,6 +61,27 @@ def test_deadline_fires_with_zero_caller_traffic():
     assert (r.pred >= 0).all() and (r.confidence == 1.0).all()
 
 
+def test_driver_failure_surfaces_to_writers():
+    """A tick that raises ends the driver's loop; the exception reaches
+    the next writer and ``stop`` instead of dying with the thread."""
+    svc = _service(window_ops=1000, window_ms=5.0)
+
+    def broken_submit(batch):
+        raise ValueError("solve failed")
+
+    svc.engine.submit = broken_submit
+    svc.start()
+    driver = svc._driver
+    svc.mutate(*_labeled(4))  # below the size bound: the deadline admits
+    _wait_until(lambda: driver.error is not None, msg="driver failure")
+    assert not driver.is_alive()
+    with pytest.raises(RuntimeError, match="service driver failed") as e:
+        svc.sync()
+    assert isinstance(e.value.__cause__, ValueError)
+    with pytest.raises(RuntimeError, match="service driver failed"):
+        svc.stop()
+
+
 def test_concurrent_readers_never_torn_across_commits():
     """Reader threads hammer the service while commits land mid-burst.
 

@@ -1,10 +1,6 @@
 """Property-based stream equivalence: for ANY random mixed insert/delete
 stream, ``StreamEngine`` labels are bit-identical to a full per-batch
 ``DynLP`` recompute, on both the ``ref`` and ``ell_pallas`` backends.
-
-Strategies use only the surface shared by real hypothesis and the
-``tests/_hypothesis_fallback.py`` shim (integers / floats / booleans /
-sampled_from), so the suite runs identically with either installed.
 """
 
 import numpy as np
@@ -110,6 +106,35 @@ def test_pipelined_stream_bit_identical_to_dynlp(seed, n_batches,
     assert eng.drain() is not None
     done += 1
     assert done == len(batches) == eng.commits
+    np.testing.assert_array_equal(g_p.f, g_d.f)
+
+
+@given(st.integers(0, 10_000), st.integers(3, 5), st.integers(10, 24))
+@settings(max_examples=6, deadline=None)
+def test_pipelined_relabels_bit_identical_to_dynlp(seed, n_batches,
+                                                   batch_size):
+    """Relabels that land while the previous batch is still in flight
+    (a seed promoted, demoted, or an unlabeled row reset to 0.5) keep the
+    sequential order: the drained solve must not overwrite them."""
+    batches = _random_batches(seed, n_batches, batch_size, 0.1,
+                              hostile_dels=False, include_empty=False)
+    rng = np.random.default_rng(seed + 1)
+    seen = 0
+    for b in batches:
+        if seen:
+            b.rel_ids = rng.choice(seen, min(seen, 6), replace=False)
+            b.rel_labels = rng.choice(
+                np.array([0, 1, UNLABELED], np.int8), len(b.rel_ids))
+        seen += len(b.ins_emb)
+    g_p = DynamicGraph(emb_dim=EMB_DIM, k=4)
+    g_d = DynamicGraph(emb_dim=EMB_DIM, k=4)
+    eng = StreamEngine(g_p, delta=1e-4)
+    dyn = DynLP(g_d, delta=1e-4)
+    for batch in batches:
+        eng.submit(batch)
+        dyn.step(batch)
+    eng.drain()
+    np.testing.assert_array_equal(g_p.labels, g_d.labels)
     np.testing.assert_array_equal(g_p.f, g_d.f)
 
 
