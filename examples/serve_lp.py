@@ -55,6 +55,12 @@ def estimator_quickstart():
           f"vertices after partial_fit\n")
 
 
+def _mean_ms(spans: dict, name: str) -> float:
+    """Mean milliseconds of a span from ``ServiceStats.spans``."""
+    count, total_ms = spans[name]
+    return total_ms / count
+
+
 def serving_demo():
     spec = StreamSpec(total_vertices=900, batch_size=60, seed=0,
                       class_sep=6.0, noise=0.9)
@@ -89,8 +95,9 @@ def serving_demo():
     print(f"served {st.queries} query calls ({st.query_nodes} node lookups, "
           f"{st.queries_while_inflight} mid-flight) against "
           f"{st.mutations} mutations in {st.batches_committed} windows | "
-          f"commit p50={st.commit_latency_ms['p50']:.1f} ms "
-          f"p95={st.commit_latency_ms['p95']:.1f} ms | "
+          f"write-lock wait {_mean_ms(st.spans, 'lp.mutate.lock'):.2f} ms, "
+          f"window open {_mean_ms(st.spans, 'lp.window.wait'):.1f} ms, "
+          f"submit {_mean_ms(st.spans, 'engine.submit'):.1f} ms (means) | "
           f"{st.recompiles} recompiles over {st.bucket_rungs} bucket rungs\n")
 
 

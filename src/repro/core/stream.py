@@ -96,6 +96,7 @@ from repro.core.snapshot import (DeviceLabelView, HostSnapshot, LabelView,
                                  apply_halo_layout, bucket, bucket_k,
                                  build_host_problem, publish_device_view,
                                  reorder_host_snapshot)
+from repro.core.trace import Recorder
 from repro.graph import partition
 from repro.graph.dynamic import UNLABELED, BatchUpdate, DynamicGraph
 from repro.kernels import ops
@@ -166,6 +167,7 @@ class _Pending:
     # relabel already reset their f, and the sequential order (solve, then
     # relabel) says it wins, so drain must not overwrite them
     relabelled: np.ndarray | None = None
+    dispatched_at: float = 0.0  # perf_counter at the end of its submit
 
 
 @dataclasses.dataclass
@@ -365,6 +367,10 @@ class StreamEngine:
         # every drain, never mutated in place — readers hold a consistent
         # view while the next batch's solve is in flight.
         self._view = LabelView.from_graph(graph, commit_id=0)
+        self.last_commit_at: float | None = None  # perf_counter, end of drain
+        # spans/counters of submit and drain (core.trace); LPService
+        # records its admission and ack spans here too
+        self.trace = Recorder()
         # Device twin of the committed view: published lazily on the
         # first ``device_view()`` call, then eagerly at every drain (the
         # H2D dispatches async, overlapping the next batch's host work).
@@ -770,6 +776,9 @@ class StreamEngine:
     ) -> PropagationProblem:
         """Stage a host snapshot into the persistent device buffers."""
         key = host.bucket_key
+        self.trace.add("engine.h2d_bytes", sum(
+            a.nbytes for a in (host.nbr, host.wgt, host.wl0, host.wl1,
+                               host.valid)))
         if plan is not None:  # mesh mode: row-sharded staging
             new = plan.put_problem(host.nbr, host.wgt, host.wl0, host.wl1,
                                    host.valid)
@@ -798,33 +807,48 @@ class StreamEngine:
     # ------------------------------------------------------------------ #
     def submit(self, batch: BatchUpdate) -> StreamStats | None:
         """Apply Δ_t, stage it, launch its solve; returns the now-complete
-        stats of the PREVIOUS batch (None on the first call)."""
+        stats of the PREVIOUS batch (None on the first call).
+
+        Spans (``self.trace``, each tagged ``batch=`` with the commit id
+        this Δ_t will get): ``engine.submit`` around the whole call, and
+        its children ``engine.submit.apply`` / ``.build`` / ``.stage`` /
+        ``.supernode`` / ``.dispatch`` and ``engine.drain`` of the
+        previous batch; ``engine.h2d_bytes`` counts the host arrays
+        handed to the device."""
+        b = self.batches + 1
+        with self.trace.span("engine.submit", batch=b):
+            return self._submit(batch, b)
+
+    def _submit(self, batch: BatchUpdate, b: int) -> StreamStats | None:
         t0 = time.perf_counter()
         g = self.graph
+        span = self.trace.span
 
-        # ---- Step 0: arrival ordering (ids are assigned in row order,
-        # so this must run before apply_batch) ----
-        if self.ingest_order == "locality" and len(batch.ins_emb) > 2:
-            from repro.data.synth import cosine_locality_order
-            order = cosine_locality_order(
-                np.asarray(batch.ins_emb, np.float32))
-            batch = dataclasses.replace(
-                batch, ins_emb=np.asarray(batch.ins_emb)[order],
-                ins_labels=np.asarray(batch.ins_labels)[order])
+        with span("engine.submit.apply", batch=b):
+            # ---- Step 0: arrival ordering (ids are assigned in row
+            # order, so this must run before apply_batch) ----
+            if self.ingest_order == "locality" and len(batch.ins_emb) > 2:
+                from repro.data.synth import cosine_locality_order
+                order = cosine_locality_order(
+                    np.asarray(batch.ins_emb, np.float32))
+                batch = dataclasses.replace(
+                    batch, ins_emb=np.asarray(batch.ins_emb)[order],
+                    ins_labels=np.asarray(batch.ins_labels)[order])
 
-        # ---- Step 1: change adjustment & sparsification (host) ----
-        effect = g.apply_batch(batch, tau=self.tau, selector=self.ingestor)
-        m = len(effect.new_ids)
-        if self._pending is not None and batch.rel_ids is not None \
-                and len(batch.rel_ids):
-            rel = np.asarray(batch.rel_ids, np.int64)
-            rel = rel[(rel >= 0) & (rel < g.num_nodes)]
-            rel = rel[g.alive[rel]]  # the relabels apply_batch applied
-            p = self._pending
-            p.relabelled = (rel if p.relabelled is None
-                            else np.union1d(p.relabelled, rel))
-        if self._lm is not None:
-            self._note_touched(effect)
+            # ---- Step 1: change adjustment & sparsification (host) ----
+            effect = g.apply_batch(batch, tau=self.tau,
+                                   selector=self.ingestor)
+            m = len(effect.new_ids)
+            if self._pending is not None and batch.rel_ids is not None \
+                    and len(batch.rel_ids):
+                rel = np.asarray(batch.rel_ids, np.int64)
+                rel = rel[(rel >= 0) & (rel < g.num_nodes)]
+                rel = rel[g.alive[rel]]  # the relabels apply_batch applied
+                p = self._pending
+                p.relabelled = (rel if p.relabelled is None
+                                else np.union1d(p.relabelled, rel))
+            if self._lm is not None:
+                self._note_touched(effect)
 
         # ``effect.affected`` is already alive-filtered, so the frontier
         # below is nonempty iff some affected vertex is unlabeled — an
@@ -837,113 +861,131 @@ class StreamEngine:
             # and dispatch entirely.  The batch still commits — drain()
             # publishes a LabelView reflecting any alive/labels changes.
             prev = self.drain()
-            self.batches += 1
-            unl_ids = np.flatnonzero(g.alive & (g.labels == UNLABELED))
-            self._pending = _Pending(
-                res=None, unl_ids=unl_ids, t0=t0,
-                num_components=0, frontier_size=0,
-                bucket=(0, 0),  # nothing staged this Δ_t
-                recompiled=False, transport="none", backend="none",
-                view_labels=g.labels.copy(), view_alive=g.alive.copy(),
-                view_f=g.f.copy(),
-            )
+            with span("engine.submit.dispatch", batch=b):
+                self.batches += 1
+                unl_ids = np.flatnonzero(g.alive & (g.labels == UNLABELED))
+                self._pending = _Pending(
+                    res=None, unl_ids=unl_ids, t0=t0,
+                    num_components=0, frontier_size=0,
+                    bucket=(0, 0),  # nothing staged this Δ_t
+                    recompiled=False, transport="none", backend="none",
+                    view_labels=g.labels.copy(), view_alive=g.alive.copy(),
+                    view_f=g.f.copy(), dispatched_at=time.perf_counter(),
+                )
             return prev
 
-        # ---- landmark hot/cold gate: decided BEFORE the snapshot build
-        # (the hot restriction changes the bucket this Δ_t lands in) ----
-        hot = self._landmark_gate() if self._lm is not None else None
-        cold_ids = None
-        if hot is not None:
-            cold_ids = np.flatnonzero(g.alive & (g.labels == UNLABELED)
-                                      & ~hot)
+        with span("engine.submit.build", batch=b):
+            # ---- landmark hot/cold gate: decided BEFORE the snapshot
+            # build (the hot restriction changes the bucket this Δ_t
+            # lands in) ----
+            hot = self._landmark_gate() if self._lm is not None else None
+            cold_ids = None
+            if hot is not None:
+                cold_ids = np.flatnonzero(g.alive & (g.labels == UNLABELED)
+                                          & ~hot)
 
-        # ---- stage batch-t topology while batch t-1 still propagates ----
-        host = build_host_problem(g, max_degree=self.max_degree,
-                                  auto_bucket=True,
-                                  row_multiple=self._row_multiple,
-                                  max_k=self.max_k,
-                                  warned=self._max_k_warned,
-                                  hot=hot)
-        if hot is not None:
-            # the hot/cold contract overrides the rung's registry scan —
-            # a hot problem is small by design, so per-rung auto would
-            # pick an exact backend and mislabel approximate batches
-            self._backend_modes[host.bucket_key] = "landmark"
-        u = len(host.unl_ids)
-        u_pad = len(host.valid)
-        frontier = np.zeros(u_pad, bool)
-        aff_rows = host.remap[effect.affected]
-        frontier[aff_rows[aff_rows >= 0]] = True
+            # ---- stage batch-t topology while batch t-1 still
+            # propagates ----
+            host = build_host_problem(g, max_degree=self.max_degree,
+                                      auto_bucket=True,
+                                      row_multiple=self._row_multiple,
+                                      max_k=self.max_k,
+                                      warned=self._max_k_warned,
+                                      hot=hot)
+            if hot is not None:
+                # the hot/cold contract overrides the rung's registry
+                # scan — a hot problem is small by design, so per-rung
+                # auto would pick an exact backend and mislabel
+                # approximate batches
+                self._backend_modes[host.bucket_key] = "landmark"
+            u = len(host.unl_ids)
+            u_pad = len(host.valid)
+            frontier = np.zeros(u_pad, bool)
+            aff_rows = host.remap[effect.affected]
+            frontier[aff_rows[aff_rows >= 0]] = True
 
-        # resolve this batch's backend/transport/plan through the per-rung
-        # registry state; bsr and halo batches permute the snapshot (into
-        # component order or the export-prefix layout) before staging —
-        # row order is invisible to the fixpoint, so labels stay bit-equal.
-        # ``host`` itself stays in original row order for the supernode
-        # init and f0 builds below, which fold back via ``st.rows``.
-        st = (self._stage_mesh(host) if self.mesh is not None
-              else self._stage_single(host))
-        plan = st.plan
-        problem = self._commit(st.staged, plan)
-        frontier_staged = frontier if st.perm is None else frontier[st.perm]
-        frontier_dev = (plan.put_row(frontier_staged) if plan is not None
-                        else jnp.asarray(frontier_staged))
+        with span("engine.submit.stage", batch=b):
+            # resolve this batch's backend/transport/plan through the
+            # per-rung registry state; bsr and halo batches permute the
+            # snapshot (into component order or the export-prefix layout)
+            # before staging — row order is invisible to the fixpoint, so
+            # labels stay bit-equal.  ``host`` itself stays in original
+            # row order for the supernode init and f0 builds below, which
+            # fold back via ``st.rows``.
+            st = (self._stage_mesh(host) if self.mesh is not None
+                  else self._stage_single(host))
+            plan = st.plan
+            problem = self._commit(st.staged, plan)
+            frontier_staged = (frontier if st.perm is None
+                               else frontier[st.perm])
+            self.trace.add("engine.h2d_bytes", frontier_staged.nbytes)
+            frontier_dev = (plan.put_row(frontier_staged) if plan is not None
+                            else jnp.asarray(frontier_staged))
 
-        # ---- Step 2: supernode label initialization (host wl0/wl1) ----
-        n_components = 0
-        new_unl = effect.new_ids[g.labels[effect.new_ids] == UNLABELED]
-        if m and len(new_unl):
-            comp_local = gprime_components(effect, m)
-            local_idx = new_unl - effect.new_ids[0]
-            comp = compact_labels(jnp.asarray(comp_local))[local_idx]
-            n_components = int(jnp.max(comp) + 1) if len(local_idx) else 0
-            rows = host.remap[new_unl]
-            f_init = supernode_init(
-                comp, jnp.asarray(host.wl0[rows]), jnp.asarray(host.wl1[rows]),
-                num_segments=max(m, 1))
-            g.f[new_unl] = np.asarray(f_init)
+        with span("engine.submit.supernode", batch=b):
+            # ---- Step 2: supernode label initialization (host wl0/wl1) --
+            n_components = 0
+            new_unl = effect.new_ids[g.labels[effect.new_ids] == UNLABELED]
+            if m and len(new_unl):
+                comp_local = gprime_components(effect, m)
+                local_idx = new_unl - effect.new_ids[0]
+                comp = compact_labels(jnp.asarray(comp_local))[local_idx]
+                n_components = int(jnp.max(comp) + 1) if len(local_idx) else 0
+                rows = host.remap[new_unl]
+                wl0, wl1 = host.wl0[rows], host.wl1[rows]
+                self.trace.add("engine.h2d_bytes", wl0.nbytes + wl1.nbytes)
+                f_init = supernode_init(
+                    comp, jnp.asarray(wl0), jnp.asarray(wl1),
+                    num_segments=max(m, 1))
+                g.f[new_unl] = np.asarray(f_init)
 
         # ---- drain batch t-1 (first moment its result is truly needed:
         # f0 below reads the propagated labels) ----
         prev = self.drain()
 
-        # ---- Step 3: launch this batch's solve (async) ----
-        f0 = np.full(u_pad, 0.5, np.float32)
-        f0[:u] = g.f[host.unl_ids]
-        if st.perm is not None:
-            f0 = f0[st.perm]
-        # f0 is donated into the solve in both modes; in mesh mode it is
-        # staged row-sharded first so each device recycles its own block.
-        f0_dev = plan.put_row(f0) if plan is not None else jnp.asarray(f0)
-        slot_dev = None
-        if st.slot is not None:
-            slot_dev = (plan.put_row2(st.slot) if plan is not None
-                        else jnp.asarray(st.slot))
-        before = ops.compile_cache_size()
-        res = ops.run_propagation(
-            problem, f0_dev, frontier_dev,
-            delta=self.delta, max_iters=self.max_iters,
-            backend=st.backend, block_rows=self.block_rows,
-            interpret=self.interpret, donate=True, shard_plan=plan,
-            slot=slot_dev, num_slots=st.num_slots or None,
-            block_size=self._bsr_block if st.backend == "bsr" else None,
-        )
-        recompiled = ops.compile_cache_size() > before
-        self.recompile_count += recompiled
-        self.batches += 1
-        self._pending = _Pending(
-            res=res, unl_ids=host.unl_ids, t0=t0,
-            num_components=n_components, frontier_size=int(frontier.sum()),
-            bucket=host.bucket_key, recompiled=recompiled,
-            transport=st.transport, backend=st.backend,
-            rows=st.rows, cold_ids=cold_ids,
-            # Batch-t host state (labels/alive fixed by apply_batch above;
-            # f now holds batch t-1's committed labels plus this batch's
-            # supernode inits).  drain() folds the solved rows over view_f
-            # and publishes the result as the committed LabelView.
-            view_labels=g.labels.copy(), view_alive=g.alive.copy(),
-            view_f=g.f.copy(),
-        )
+        with span("engine.submit.dispatch", batch=b):
+            # ---- Step 3: launch this batch's solve (async) ----
+            f0 = np.full(u_pad, 0.5, np.float32)
+            f0[:u] = g.f[host.unl_ids]
+            if st.perm is not None:
+                f0 = f0[st.perm]
+            # f0 is donated into the solve in both modes; in mesh mode it
+            # is staged row-sharded first so each device recycles its own
+            # block.
+            f0_dev = plan.put_row(f0) if plan is not None else jnp.asarray(f0)
+            self.trace.add("engine.h2d_bytes", f0.nbytes)
+            slot_dev = None
+            if st.slot is not None:
+                self.trace.add("engine.h2d_bytes", st.slot.nbytes)
+                slot_dev = (plan.put_row2(st.slot) if plan is not None
+                            else jnp.asarray(st.slot))
+            before = ops.compile_cache_size()
+            res = ops.run_propagation(
+                problem, f0_dev, frontier_dev,
+                delta=self.delta, max_iters=self.max_iters,
+                backend=st.backend, block_rows=self.block_rows,
+                interpret=self.interpret, donate=True, shard_plan=plan,
+                slot=slot_dev, num_slots=st.num_slots or None,
+                block_size=self._bsr_block if st.backend == "bsr" else None,
+            )
+            recompiled = ops.compile_cache_size() > before
+            self.recompile_count += recompiled
+            self.batches += 1
+            self._pending = _Pending(
+                res=res, unl_ids=host.unl_ids, t0=t0,
+                num_components=n_components,
+                frontier_size=int(frontier.sum()),
+                bucket=host.bucket_key, recompiled=recompiled,
+                transport=st.transport, backend=st.backend,
+                rows=st.rows, cold_ids=cold_ids,
+                # Batch-t host state (labels/alive fixed by apply_batch
+                # above; f now holds batch t-1's committed labels plus
+                # this batch's supernode inits).  drain() folds the
+                # solved rows over view_f and publishes the result as the
+                # committed LabelView.
+                view_labels=g.labels.copy(), view_alive=g.alive.copy(),
+                view_f=g.f.copy(), dispatched_at=time.perf_counter(),
+            )
         return prev
 
     # ------------------------------------------------------------------ #
@@ -954,14 +996,22 @@ class StreamEngine:
         Draining COMMITS the batch: the committed ``LabelView`` is
         rebuilt here (solved rows folded over the state captured at
         submit), so ``committed_view()`` readers flip atomically from
-        batch t-1's labels to batch t's."""
+        batch t-1's labels to batch t's.  Spans: ``engine.drain``, and
+        ``engine.drain.wait`` while the host blocks on the device; the
+        interval ``engine.inflight`` runs from the end of the submit that
+        dispatched a solve to its commit here."""
         p, self._pending = self._pending, None
         if p is None:
             return None
+        with self.trace.span("engine.drain", batch=self.commits + 1):
+            return self._drain(p)
+
+    def _drain(self, p: _Pending) -> StreamStats:
         if p.res is None:  # no-op batch: nothing was solved
             iterations, converged, resid = 0, True, 0.0
         else:
-            f = np.asarray(p.res.f)  # synchronizes
+            with self.trace.span("engine.drain.wait"):
+                f = np.asarray(p.res.f)  # synchronizes
             # halo/bsr batches solved in a permuted row order: gather the
             # original rows back through the layout's inverse permutation
             solved = f[p.rows] if p.rows is not None else f[: len(p.unl_ids)]
@@ -986,13 +1036,17 @@ class StreamEngine:
         if self._device_view is not None:
             self._device_view = publish_device_view(self._view,
                                                     self._read_placement)
+        self.last_commit_at = time.perf_counter()
+        if p.res is not None:
+            self.trace.interval("engine.inflight",
+                                self.last_commit_at - p.dispatched_at)
         return StreamStats(
             iterations=iterations,
             converged=converged,
             num_components=p.num_components,
             frontier_size=p.frontier_size,
             num_unlabeled=len(p.unl_ids),
-            wall_ms=(time.perf_counter() - p.t0) * 1e3,
+            wall_ms=(self.last_commit_at - p.t0) * 1e3,
             max_residual=resid,
             bucket=p.bucket,
             recompiled=p.recompiled,

@@ -388,7 +388,10 @@ class DynamicGraph:
         if batch.rel_ids is not None and len(batch.rel_ids):
             rel = np.asarray(batch.rel_ids, np.int64)
             rlab = np.asarray(batch.rel_labels, np.int8)
-            ok = (rel >= 0) & (rel < self.num_nodes) & self.alive[rel]
+            # range first: ids past the last row are dropped, like deletes
+            ok = (rel >= 0) & (rel < self.num_nodes)
+            rel, rlab = rel[ok], rlab[ok]
+            ok = self.alive[rel]
             rel, rlab = rel[ok], rlab[ok]
             if len(rel):
                 self.labels[rel] = rlab

@@ -168,9 +168,13 @@ class ServiceDriver(threading.Thread):
                 t._fulfil(error=e)
 
     def _loop(self):
+        trace = self._svc.engine.trace
         while True:
             tickets = self._batcher.take_all()
             if tickets:
+                now = time.perf_counter()
+                for t in tickets:
+                    trace.interval("lp.read.queue", now - t.enqueued_at)
                 self._serve(tickets)
             admitted = self._svc._driver_pump()
             self.deadline_admissions += admitted

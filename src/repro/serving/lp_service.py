@@ -21,9 +21,17 @@ Commit flow: ``submit`` pipelines; ``poll`` (called from ``pump`` /
 ``mutate``) commits a finished solve without blocking; ``sync`` flushes
 the open window and blocks until everything admitted has committed —
 after ``sync()`` returns, queries see every prior mutation
-(read-your-writes).  Each mutation gets a ``MutationTicket`` whose
-commit latency feeds the service stats (``benchmarks/serve_lp.py``
-reports the percentiles).
+(read-your-writes).  Each mutation gets a ``MutationTicket`` stamped at
+enqueue, admission and commit.
+
+Spans and counters: the service records into its engine's recorder
+(``StreamEngine.trace``, ``core.trace``), and ``stats()`` returns their
+totals (``ServiceStats.spans``/``counters``): the write-lock wait of
+``mutate``, backpressure relief, the time a window stays open, the admit,
+the engine's submit phases and drain, the time a dispatched solve waits
+for its commit, the time tickets wait after their view is published, and
+the read queue and fused gathers.  See docs/serving.md §Spans and
+counters.
 
 Async serving: ``start()`` (or ``with service:``) launches a background
 ``serving.engine.ServiceDriver`` thread.  The driver clocks admission —
@@ -62,7 +70,6 @@ mutation streams.
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import threading
 import time
@@ -88,8 +95,9 @@ class MutationTicket:
 
     ticket: int
     ops: int  # inserted vertices + delete requests in this mutation
-    enqueued_at: float  # perf_counter at enqueue
-    committed_at: float | None = None
+    enqueued_at: float  # perf_counter at enqueue (write lock held)
+    admitted_at: float | None = None  # its window handed to the engine
+    committed_at: float | None = None  # its tickets resolved
     commit_id: int | None = None  # engine commit that made it visible
 
     @property
@@ -134,7 +142,8 @@ class ServiceStats:
     pending_ops: int  # queued (window) + in-flight right now
     recompiles: int  # engine recompile count (bucket-ladder bounded)
     bucket_rungs: int
-    commit_latency_ms: dict  # p50/p95/p99/max over the last <=4096 commits
+    spans: dict  # {span or interval name: (count, total ms)} (core.trace)
+    counters: dict  # {counter name: value}
     transport: dict  # StreamEngine.transport_summary(): requested knob,
     # per-rung allgather/halo decisions, halo batch + overflow counts
     checkpoints_written: int = 0  # policy snapshots taken (async + final)
@@ -217,10 +226,6 @@ class LPService:
         self._inflight: list[MutationTicket] = []
         self._inflight_ops = 0
         self._next_ticket = 0
-        # Rolling window: a long-lived service must not grow a per-
-        # mutation history (or re-percentile it) without bound.
-        self._commit_latency_ms: collections.deque[float] = \
-            collections.deque(maxlen=4096)
         # One reentrant lock guards the engine's WRITE side (window
         # state, submit/poll/drain) — callers and the driver thread both
         # clock the service through it.  Reads deliberately take only
@@ -450,7 +455,8 @@ class LPService:
         cut_cat = np.concatenate(
             [np.full(len(t.ids), t.cutoff, np.float32) for t in tickets]) \
             if tickets else np.zeros(0, np.float32)
-        pred, conf = view.query(ids_cat, cut_cat)
+        with self.engine.trace.span("lp.read.serve"):
+            pred, conf = view.query(ids_cat, cut_cat)
         out, off = [], 0
         for t in tickets:
             q = len(t.ids)
@@ -542,7 +548,10 @@ class LPService:
             raise ValueError(
                 "empty mutation: no inserts, deletes or relabels")
 
-        with self._lock:
+        trace = self.engine.trace
+        with trace.span("lp.mutate.lock"):
+            self._lock.acquire()
+        try:
             if self.preempted:
                 raise RuntimeError(
                     "service preempted: state was checkpointed and the "
@@ -557,7 +566,8 @@ class LPService:
                         f"mutation of {ops} ops over bound: "
                         f"{self._pending_ops()} pending, "
                         f"max_pending_ops={self.max_pending_ops}")
-                self._relieve(ops)
+                with trace.span("lp.mutate.relieve"):
+                    self._relieve(ops)
 
             ticket = MutationTicket(ticket=self._next_ticket, ops=ops,
                                     enqueued_at=time.perf_counter())
@@ -572,6 +582,8 @@ class LPService:
             if self._window_ops >= self.window_ops:
                 self._admit()
             return ticket
+        finally:
+            self._lock.release()
 
     def pump(self) -> StreamStats | None:
         """Advance the service without blocking: commit the in-flight
@@ -670,33 +682,41 @@ class LPService:
         """Coalesce the window into one BatchUpdate and submit it."""
         if not self._window:
             return None
-        window, self._window = self._window, []
-        ops, self._window_ops = self._window_ops, 0
-        self._window_t0 = None
-        batch = BatchUpdate(
-            ins_emb=np.concatenate([q.ins_emb for q in window]),
-            ins_labels=np.concatenate([q.ins_labels for q in window]),
-            del_ids=np.concatenate([q.del_ids for q in window]),
-            rel_ids=np.concatenate([q.rel_ids for q in window]),
-            rel_labels=np.concatenate([q.rel_labels for q in window]),
-        )
-        # submit internally drains the previous batch — those are the
-        # current in-flight tickets, resolved below if that drain ran.
-        prev = self.engine.submit(batch)
-        if prev is not None:
-            self._resolve(prev)
-        self._inflight = [q.ticket for q in window]
-        self._inflight_ops = ops
-        self.batches_admitted += 1
+        now = time.perf_counter()
+        trace = self.engine.trace
+        trace.interval("lp.window.wait", now - self._window_t0)
+        with trace.span("lp.admit"):
+            window, self._window = self._window, []
+            ops, self._window_ops = self._window_ops, 0
+            self._window_t0 = None
+            for q in window:
+                q.ticket.admitted_at = now
+            batch = BatchUpdate(
+                ins_emb=np.concatenate([q.ins_emb for q in window]),
+                ins_labels=np.concatenate([q.ins_labels for q in window]),
+                del_ids=np.concatenate([q.del_ids for q in window]),
+                rel_ids=np.concatenate([q.rel_ids for q in window]),
+                rel_labels=np.concatenate([q.rel_labels for q in window]),
+            )
+            # submit internally drains the previous batch — those are the
+            # current in-flight tickets, resolved below if that drain ran.
+            prev = self.engine.submit(batch)
+            if prev is not None:
+                self._resolve(prev)
+            self._inflight = [q.ticket for q in window]
+            self._inflight_ops = ops
+            self.batches_admitted += 1
         return batch
 
     def _resolve(self, stats: StreamStats):
-        """Mark the in-flight tickets committed (their batch drained)."""
+        """Mark the in-flight tickets committed (their batch drained);
+        ``lp.ack.lag`` is the time since that drain published the view."""
         now = time.perf_counter()
+        self.engine.trace.interval("lp.ack.lag",
+                                   now - self.engine.last_commit_at)
         for t in self._inflight:
             t.committed_at = now
             t.commit_id = self.engine.commits
-            self._commit_latency_ms.append(t.latency_ms)
         self._inflight = []
         self._inflight_ops = 0
         self.batches_committed += 1
@@ -704,18 +724,9 @@ class LPService:
 
     # ------------------------------------------------------------------ #
     def stats(self) -> ServiceStats:
-        """Current service counters plus commit-latency percentiles."""
-        lat = self._commit_latency_ms
-        pct = {}
-        if lat:
-            arr = np.asarray(lat)
-            pct = {
-                "p50": round(float(np.percentile(arr, 50)), 3),
-                "p95": round(float(np.percentile(arr, 95)), 3),
-                "p99": round(float(np.percentile(arr, 99)), 3),
-                "max": round(float(arr.max()), 3),
-                "count": len(lat),
-            }
+        """Current service counters plus the recorder's span and counter
+        totals (take two and difference them to cover an interval)."""
+        spans, counters = self.engine.trace.snapshot()
         d = self._driver
         rb, rt, da = self._drained_reads
         if d is not None:
@@ -738,7 +749,8 @@ class LPService:
             pending_ops=self._pending_ops(),
             recompiles=self.engine.recompile_count,
             bucket_rungs=len(self.engine.bucket_keys),
-            commit_latency_ms=pct,
+            spans=spans,
+            counters=counters,
             transport=self.engine.transport_summary(),
             checkpoints_written=self.checkpoints_written,
             last_checkpoint_commit=self._last_ckpt_commit,

@@ -6,6 +6,7 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import numpy as np
 import pytest
@@ -128,7 +129,7 @@ def test_pipelined_windows_match_sync_per_batch():
                                   synced.committed_view().f)
     st = piped.stats()
     assert st.batches_admitted == st.batches_committed == 5
-    assert st.commit_latency_ms["count"] == st.mutations
+    assert st.spans["lp.mutate.lock"][0] == st.mutations
 
 
 def test_admission_window_deadline_and_size():
@@ -273,6 +274,26 @@ def test_service_stats_counts():
     assert st.queries == 5 and st.query_nodes == 20
     assert st.queries_while_inflight == 5
     assert st.pending_ops == 0 and st.rejected == 0
-    assert st.commit_latency_ms["count"] == 5
-    assert st.commit_latency_ms["p50"] <= st.commit_latency_ms["max"]
+    assert st.spans["lp.ack.lag"][0] == 5
+    assert st.spans["engine.submit"][0] == st.spans["engine.drain"][0] == 5
     assert st.recompiles <= st.bucket_rungs
+
+
+@pytest.mark.parametrize("past", [0, 1, 1_000])
+def test_relabel_past_last_row_is_dropped_and_driver_lives(past):
+    """A relabel of an id that no row has yet is dropped like an
+    out-of-range delete; it must not kill the driver that admits it."""
+    g = DynamicGraph(emb_dim=SPEC.emb_dim, k=5)
+    svc = _service(g, window_ms=1.0)
+    batch = next(iter(gaussian_mixture_stream(SPEC)))[0]
+    svc.add_points(batch.ins_emb, batch.ins_labels)
+    svc.sync()
+    labels = g.labels.copy()
+    with svc:
+        t = svc.relabel([g.num_nodes + past, 0], [1, 1])
+        deadline = time.perf_counter() + 60
+        while not t.committed and time.perf_counter() < deadline:
+            time.sleep(0.005)
+        assert t.committed and svc.driver_running
+    labels[0] = 1
+    np.testing.assert_array_equal(g.labels, labels)
