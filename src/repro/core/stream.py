@@ -99,6 +99,7 @@ from repro.core.snapshot import (DeviceLabelView, HostSnapshot, LabelView,
 from repro.core.trace import Recorder
 from repro.graph import partition
 from repro.graph.dynamic import UNLABELED, BatchUpdate, DynamicGraph
+from repro.ingest import DeviceIngestor
 from repro.kernels import ops
 from repro.kernels.bsr_spmv import ell_bsr_layout
 from repro.kernels.landmark_propagate import LandmarkConfig, LandmarkState
@@ -220,7 +221,6 @@ class StreamEngine:
         if ingest in (None, "host"):
             self.ingestor = None
         elif ingest == "device":
-            from repro.ingest import DeviceIngestor
             self.ingestor = DeviceIngestor(graph.emb_dim, mesh=mesh)
             if graph.num_nodes:
                 self.ingestor.attach(graph)
@@ -1066,6 +1066,17 @@ class StreamEngine:
         if p.res is not None and not p.res.f.is_ready():
             return None
         return self.drain()
+
+    def waits_on_solve(self, batch: BatchUpdate) -> bool:
+        """Whether ``submit(batch)`` would block on the in-flight solve
+        before its own drain: the batch inserts rows and the engine
+        ingests on the device, so ``DeviceIngestor.select`` reads its
+        candidates back from a device that runs programs in order, behind
+        the solve.  Committing first (``drain``) then costs the staging
+        nothing and makes the solved batch readable a whole staging
+        earlier; ``LPService`` does so at the head of an admit."""
+        return (self._pending is not None and len(batch.ins_emb) > 0
+                and isinstance(self.ingestor, DeviceIngestor))
 
     @property
     def in_flight(self) -> bool:

@@ -18,9 +18,11 @@ a front-end for the two request kinds a label service sees:
     host staging of window t+1 overlaps device propagation of window t.
 
 Commit flow: ``submit`` pipelines; ``poll`` (called from ``pump`` /
-``mutate``) commits a finished solve without blocking; ``sync`` flushes
-the open window and blocks until everything admitted has committed —
-after ``sync()`` returns, queries see every prior mutation
+``mutate``) commits a finished solve without blocking; an admit whose
+staging would wait for the in-flight solve anyway
+(``StreamEngine.waits_on_solve``) commits it before staging; ``sync``
+flushes the open window and blocks until everything admitted has
+committed — after ``sync()`` returns, queries see every prior mutation
 (read-your-writes).  Each mutation gets a ``MutationTicket`` stamped at
 enqueue, admission and commit.
 
@@ -698,8 +700,14 @@ class LPService:
                 rel_ids=np.concatenate([q.rel_ids for q in window]),
                 rel_labels=np.concatenate([q.rel_labels for q in window]),
             )
-            # submit internally drains the previous batch — those are the
-            # current in-flight tickets, resolved below if that drain ran.
+            # staging that reads back from the device would wait for the
+            # in-flight solve anyway: commit it first, so its tickets
+            # resolve before this window's staging rather than after it
+            if self.engine.waits_on_solve(batch):
+                self._resolve(self.engine.drain())
+                trace.add("lp.commit.early", 1)
+            # otherwise submit drains the previous batch itself — those are
+            # the current in-flight tickets, resolved below if it ran.
             prev = self.engine.submit(batch)
             if prev is not None:
                 self._resolve(prev)

@@ -297,3 +297,95 @@ def test_relabel_past_last_row_is_dropped_and_driver_lives(past):
         assert t.committed and svc.driver_running
     labels[0] = 1
     np.testing.assert_array_equal(g.labels, labels)
+
+
+COMMIT_SPEC = StreamSpec(total_vertices=300, batch_size=60, seed=7,
+                         class_sep=6.0, noise=0.9, frac_unlabeled=0.8,
+                         frac_labeled=0.11)
+
+
+@pytest.mark.parametrize("ingest,inserts", [("device", True),
+                                            ("device", False),
+                                            ("host", True)])
+def test_commit_order_matches_step_stream(monkeypatch, ingest, inserts):
+    """An admit whose staging reads back from the device (inserts, device
+    ingest) commits the in-flight batch first: window t's tickets hold
+    commit t before window t+1's submit starts, and every commit's view
+    is byte-identical to a ``StreamEngine.step`` twin over the same
+    coalesced batches.  Windows without inserts and host ingest keep the
+    old order: the commit lands inside submit, ``lp.commit.early`` stays
+    0, and the views are the same."""
+    rng = np.random.default_rng(5)
+    g = DynamicGraph(emb_dim=COMMIT_SPEC.emb_dim, k=5)
+    eng = StreamEngine(g, delta=1e-4, ingest=ingest)
+    svc = LPService(eng, window_ops=10_000, window_ms=1e9,
+                    max_pending_ops=100_000)
+    twin = StreamEngine(DynamicGraph(emb_dim=COMMIT_SPEC.emb_dim, k=5),
+                        delta=1e-4, ingest=ingest)
+    # a busy device: poll never commits, so every admit below finds the
+    # previous window's solve in flight
+    monkeypatch.setattr(eng, "poll", lambda: None)
+    views, twin_views = {}, {}
+    drain = eng.drain
+
+    def recording_drain():
+        st = drain()
+        if st is not None:
+            views[eng.committed_view().commit_id] = eng.committed_view()
+        return st
+
+    monkeypatch.setattr(eng, "drain", recording_drain)
+    prev_tickets, at_submit = [], []
+    submit = eng.submit
+
+    def recording_submit(batch):
+        at_submit.append([(t.committed, t.commit_id) for t in prev_tickets])
+        return submit(batch)
+
+    monkeypatch.setattr(eng, "submit", recording_submit)
+
+    stream = [b for b, _ in gaussian_mixture_stream(COMMIT_SPEC)]
+    for t, batch in enumerate(stream, start=1):
+        tickets = []
+        if inserts or t == 1:
+            tickets = _split_mutations(svc, batch, parts=2)
+        else:  # deletes alone, beside the relabels below
+            tickets = [svc.remove_points(batch.del_ids)]
+        if t > 1:
+            # relabel rows the in-flight batch is solving (seeding them)
+            # and retract seeds (their f resets to 0.5): later relabels win
+            unl = np.flatnonzero(g.alive & (g.labels == UNLABELED))
+            seeds = np.flatnonzero(g.alive & (g.labels != UNLABELED))
+            ids = np.concatenate([rng.choice(unl, 6, replace=False),
+                                  rng.choice(seeds, 2, replace=False)])
+            labs = np.concatenate([rng.integers(0, 2, 6),
+                                   np.full(2, UNLABELED)]).astype(np.int8)
+            tickets.append(svc.relabel(ids, labs))
+        admitted = svc.flush()
+        twin.step(admitted)
+        twin_views[t] = twin.committed_view()
+        # window t-1 committed, here or inside submit, as commit t-1
+        assert all(q.commit_id == t - 1 for q in prev_tickets)
+        prev_tickets = tickets
+    svc.sync()
+    assert all(q.commit_id == len(stream) for q in prev_tickets)
+
+    early = ingest == "device" and inserts
+    n_early = svc.stats().counters.get("lp.commit.early", 0)
+    assert n_early == (len(stream) - 1 if early else 0)
+    # at submit of window t+1, window t has committed iff the rule engaged
+    assert at_submit[0] == []
+    for t, seen in enumerate(at_submit[1:], start=1):
+        want = (True, t) if early else (False, None)
+        assert seen and all(s == want for s in seen), (t, seen)
+
+    assert sorted(views) == sorted(twin_views) == list(
+        range(1, len(stream) + 1))
+    for c, view in views.items():
+        for name in ("f", "labels", "alive"):
+            np.testing.assert_array_equal(getattr(view, name),
+                                          getattr(twin_views[c], name),
+                                          err_msg=f"commit {c} {name}")
+    for name in ("f", "labels", "alive", "knn_idx", "src", "wgt"):
+        np.testing.assert_array_equal(getattr(g, name),
+                                      getattr(twin.graph, name))
